@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import span
+
 _SEP = "/"
 
 # Bump on any incompatible change to the on-disk layout. Version 1 =
@@ -97,6 +99,7 @@ class Checkpointer:
         if meta is not None:
             manifest["meta"] = meta
 
+        @span("sim.checkpoint_write")
         def write():
             final = os.path.join(self.dir, f"step_{step:08d}")
             tmp = final + ".tmp"

@@ -110,6 +110,16 @@ from repro.core.kde import normal_cdf
 from repro.core.oracle import step_regret
 from repro.kernels import ops as kernel_ops
 from repro.obs import recorder as obr
+from repro.obs.trace import STEP_PHASES, span
+
+# the step's phases, each a ``jax.named_scope`` (repro.obs.trace)
+(PH_CONTROL, PH_PLACEMENT, PH_MAINTENANCE, PH_TRUE_MU, PH_ROUNDS, PH_RINGS,
+ PH_ACCUMULATOR, PH_RECORDER) = STEP_PHASES
+# The phases live in op metadata, which JAX's persistent compilation
+# cache leaves out of its key by default: an executable cached before
+# the scopes changed would be loaded with the old names, and a profile
+# would attribute its ops to them. Keying on metadata keeps them true.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 @dataclass(frozen=True)
@@ -736,45 +746,47 @@ def build_sim_parts(
         # ADMITTED slot count (what the rounds execute); ``nc_sched``
         # keeps the scheduled demand for client-facing accounting. ---
         if ctl_on:
-            measf = (t_idx >= warmup_steps).astype(jnp.float32)
-            nc_sched = nc
-            ctl_cnt_pre = ctl.counters
-            ctl, act, nc, s_m, _shed = qc.control_actuate(
-                ccfg, cfg.dt, t, ctl, q, act, nc, s_m, measf)
-            # control actions for the flight recorder: this step's
-            # counter increments (already warmup-gated, replicated
-            # across shards — no collective needed to observe them)
-            ctl_deltas = (
-                ctl.counters.scale_up - ctl_cnt_pre.scale_up,
-                ctl.counters.scale_down - ctl_cnt_pre.scale_down,
-                ctl.counters.migrations - ctl_cnt_pre.migrations,
-            ) if rec_on else None
+            with jax.named_scope(PH_CONTROL):
+                measf = (t_idx >= warmup_steps).astype(jnp.float32)
+                nc_sched = nc
+                ctl_cnt_pre = ctl.counters
+                ctl, act, nc, s_m, _shed = qc.control_actuate(
+                    ccfg, cfg.dt, t, ctl, q, act, nc, s_m, measf)
+                # control actions for the flight recorder: this step's
+                # counter increments (already warmup-gated, replicated
+                # across shards — no collective needed to observe them)
+                ctl_deltas = (
+                    ctl.counters.scale_up - ctl_cnt_pre.scale_up,
+                    ctl.counters.scale_down - ctl_cnt_pre.scale_down,
+                    ctl.counters.migrations - ctl_cnt_pre.migrations,
+                ) if rec_on else None
 
         # --- scenario modulation: effective RTT and service row for
         # THIS step. The partition term is the factored rank-1 AND
         # (only LB-side ∩ instance-side routes pay the cut); with
         # neutral drivers (*1.0, +0.0) every float is unchanged. ---
-        rtt_t = rtt * rtt_scale[None, :] + jnp.minimum(
-            cut_k[:, None], cut_m[None, :])
+        with jax.named_scope(PH_PLACEMENT):
+            rtt_t = rtt * rtt_scale[None, :] + jnp.minimum(
+                cut_k[:, None], cut_m[None, :])
 
-        # --- placement events (paper Alg 3/4 trigger) ---
-        changed = jnp.any(act != prev_active)
-        if brk_on:
-            # liveness flips also clear the affected breaker columns,
-            # mirroring how Alg 3/4 purge the arm's bandit data
-            state, brk = jax.lax.cond(
-                changed,
-                lambda sb: (strat["on_activity"](sb[0], act, rtt_t, t),
-                            qb.breaker_reset_arms(sb[1],
-                                                  act != prev_active)),
-                lambda sb: sb,
-                (state, brk))
-        else:
-            state = jax.lax.cond(
-                changed,
-                lambda s: strat["on_activity"](s, act, rtt_t, t),
-                lambda s: s,
-                state)
+            # --- placement events (paper Alg 3/4 trigger) ---
+            changed = jnp.any(act != prev_active)
+            if brk_on:
+                # liveness flips also clear the affected breaker
+                # columns, mirroring how Alg 3/4 purge the arm's data
+                state, brk = jax.lax.cond(
+                    changed,
+                    lambda sb: (strat["on_activity"](sb[0], act, rtt_t, t),
+                                qb.breaker_reset_arms(sb[1],
+                                                      act != prev_active)),
+                    lambda sb: sb,
+                    (state, brk))
+            else:
+                state = jax.lax.cond(
+                    changed,
+                    lambda s: strat["on_activity"](s, act, rtt_t, t),
+                    lambda s: s,
+                    state)
 
         # --- maintenance: only the player group whose clock fires.
         # The row arrives through xs (sliced by the scan machinery from
@@ -785,30 +797,33 @@ def build_sim_parts(
         # shards read another phase's row — sharded runs then maintain
         # the wrong players (see ROADMAP; tests/test_sharded_players.py
         # is the regression net). ---
-        if subset_maint:
-            state = strat["maintain_subset"](state, rtt_t, t, group)
-        else:
-            lb_mask = jnp.zeros((K,), bool).at[group].set(
-                True, mode="drop")
-            state = strat["maintain"](state, rtt_t, t, lb_mask)
+        with jax.named_scope(PH_MAINTENANCE):
+            if subset_maint:
+                state = strat["maintain_subset"](state, rtt_t, t, group)
+            else:
+                lb_mask = jnp.zeros((K,), bool).at[group].set(
+                    True, mode="drop")
+                state = strat["maintain"](state, rtt_t, t, lb_mask)
 
-        mu_true = _true_mu(rtt_t, q, cfg, s_m)       # (K, M) at step start
-        w_now = strat["weights"](state)
-        reg = step_regret(w_now, mu_true, act)
+        with jax.named_scope(PH_TRUE_MU):
+            mu_true = _true_mu(rtt_t, q, cfg, s_m)   # (K, M) at step start
+            w_now = strat["weights"](state)
+            reg = step_regret(w_now, mu_true, act)
         q_start = q
 
-        if ctl_on:
-            mask_adm = jnp.arange(C)[None, :] < nc[:, None]    # admitted
-            mask_all = jnp.arange(C)[None, :] < nc_sched[:, None]
-        else:
-            mask_all = jnp.arange(C)[None, :] < nc[:, None]    # (K, C)
-            mask_adm = mask_all
-        # service is continuous: drain dt/C of capacity per round so
-        # in-step arrivals and departures interleave (a step-end-only
-        # drain would overstate in-step queueing by ~C/2 requests).
-        # s_m is an (M,) row, so throttled instances drain slower.
-        served_per_round = cfg.dt / (C * s_m)
-        kidx = jnp.arange(K)
+        with jax.named_scope(PH_ROUNDS):
+            if ctl_on:
+                mask_adm = jnp.arange(C)[None, :] < nc[:, None]  # admitted
+                mask_all = jnp.arange(C)[None, :] < nc_sched[:, None]
+            else:
+                mask_all = jnp.arange(C)[None, :] < nc[:, None]  # (K, C)
+                mask_adm = mask_all
+            # service is continuous: drain dt/C of capacity per round so
+            # in-step arrivals and departures interleave (a step-end-only
+            # drain would overstate in-step queueing by ~C/2 requests).
+            # s_m is an (M,) row, so throttled instances drain slower.
+            served_per_round = cfg.dt / (C * s_m)
+            kidx = jnp.arange(K)
 
         # --- request rounds: a scan, traced once instead of C times.
         # Rounds still execute in order — selection, the queue recursion
@@ -821,9 +836,10 @@ def build_sim_parts(
         # between rounds (Dec-SARSA). Bit-for-bit identical paths
         # (tests/test_bandit_batch.py). ---
         if not res_on and fused_round_on:
-            state, q, arrivals, choices, lats, procs = strat["fused_round"](
-                state, q, nc, act, t, rtt_t, s_m, served_per_round,
-                k_step, pids)
+            with jax.named_scope(PH_ROUNDS):
+                state, q, arrivals, choices, lats, procs = \
+                    strat["fused_round"](state, q, nc, act, t, rtt_t, s_m,
+                                         served_per_round, k_step, pids)
             att_kc = mask_adm.astype(jnp.int32)
             dropped_kc = jnp.zeros_like(mask_all)
             brk_open_step = None
@@ -862,15 +878,17 @@ def build_sim_parts(
                 q = jnp.maximum(q + arr_all - served_per_round, 0.0)
                 return (state, q, arrivals + arr_r), (choice, lat, proc)
 
-            (state, q, arrivals), (ch_r, lat_r, proc_r) = jax.lax.scan(
-                round_body, (state, q, jnp.zeros((M,), jnp.float32)),
-                jnp.arange(C))
-            choices = ch_r.T                                   # (K, C)
-            lats = lat_r.T
-            procs = proc_r.T
+            with jax.named_scope(PH_ROUNDS):
+                (state, q, arrivals), (ch_r, lat_r, proc_r) = jax.lax.scan(
+                    round_body, (state, q, jnp.zeros((M,), jnp.float32)),
+                    jnp.arange(C))
+                choices = ch_r.T                               # (K, C)
+                lats = lat_r.T
+                procs = proc_r.T
             if batched_record:
-                state = strat["record_rings"](state, choices, lats, t,
-                                              mask_adm)
+                with jax.named_scope(PH_RINGS):
+                    state = strat["record_rings"](state, choices, lats, t,
+                                                  mask_adm)
             att_kc = mask_adm.astype(jnp.int32)
             dropped_kc = jnp.zeros_like(mask_all)
             brk_open_step = None
@@ -884,8 +902,9 @@ def build_sim_parts(
             # round-start queue (sub-round-resolution simplification;
             # their arrivals hit the queue at the round boundary). ---
             A = n_attempts
-            brk_open_step = (qb.breaker_is_open(brk, t) if brk_on
-                             else None)
+            with jax.named_scope(PH_ROUNDS):
+                brk_open_step = (qb.breaker_is_open(brk, t) if brk_on
+                                 else None)
 
             def round_body(rc, r):
                 state, q, arrivals, brk_c = rc
@@ -986,34 +1005,38 @@ def build_sim_parts(
                       jnp.stack(att_m))
                 return (state, q, arrivals + arr, brk_c), ys
 
-            (state, q, arrivals, brk), ys_r = jax.lax.scan(
-                round_body,
-                (state, q, jnp.zeros((M,), jnp.float32), brk),
-                jnp.arange(C))
-            (chf_r, lat_r, proc_r, att_r, drop_r,
-             ach_r, aobs_r, am_r) = ys_r
-            choices = chf_r.T                 # (K, C) final-attempt arm
-            lats = lat_r.T
-            procs = proc_r.T
-            att_kc = att_r.T                  # (K, C) i32 attempts
-            dropped_kc = drop_r.T             # (K, C) bool
+            with jax.named_scope(PH_ROUNDS):
+                (state, q, arrivals, brk), ys_r = jax.lax.scan(
+                    round_body,
+                    (state, q, jnp.zeros((M,), jnp.float32), brk),
+                    jnp.arange(C))
+                (chf_r, lat_r, proc_r, att_r, drop_r,
+                 ach_r, aobs_r, am_r) = ys_r
+                choices = chf_r.T             # (K, C) final-attempt arm
+                lats = lat_r.T
+                procs = proc_r.T
+                att_kc = att_r.T              # (K, C) i32 attempts
+                dropped_kc = drop_r.T         # (K, C) bool
             if batched_record:
                 # all C*A attempts land in the step's ONE fused ring
                 # scatter, columns in chronological (round-major,
                 # attempt-minor) order — record_rings_batch is generic
                 # in its column count
-                ch_all = jnp.transpose(ach_r, (2, 0, 1)).reshape(
-                    K, C * A)
-                obs_all = jnp.transpose(aobs_r, (2, 0, 1)).reshape(
-                    K, C * A)
-                m_all = jnp.transpose(am_r, (2, 0, 1)).reshape(K, C * A)
-                state = strat["record_rings"](state, ch_all, obs_all, t,
-                                              m_all)
+                with jax.named_scope(PH_RINGS):
+                    ch_all = jnp.transpose(ach_r, (2, 0, 1)).reshape(
+                        K, C * A)
+                    obs_all = jnp.transpose(aobs_r, (2, 0, 1)).reshape(
+                        K, C * A)
+                    m_all = jnp.transpose(am_r, (2, 0, 1)).reshape(
+                        K, C * A)
+                    state = strat["record_rings"](state, ch_all, obs_all,
+                                                  t, m_all)
         # retry exhaustions for the flight recorder: snapshot the
         # deadline-dropped counts BEFORE admission sheds are merged
         # into dropped_kc below (sheds get their own event kind)
-        retry_drop_k = (dropped_kc.astype(jnp.float32).sum(-1)
-                        if rec_on and res_on else None)
+        with jax.named_scope(PH_RECORDER):
+            retry_drop_k = (dropped_kc.astype(jnp.float32).sum(-1)
+                            if rec_on and res_on else None)
         if ctl_on and ccfg.admit:
             # admission-shed slots: issued from the client's view (a
             # denied client is a failed client — shedding can only win
@@ -1021,37 +1044,39 @@ def build_sim_parts(
             # the QoS denominator) but never served: censor the
             # latency past tau, mark them dropped with zero attempts,
             # and keep them out of the routing/latency statistics.
-            shed_kc = mask_all & ~mask_adm
-            lats = jnp.where(shed_kc, jnp.inf, lats)
-            dropped_kc = dropped_kc | shed_kc
-            served_kc = mask_adm
+            with jax.named_scope(PH_CONTROL):
+                shed_kc = mask_all & ~mask_adm
+                lats = jnp.where(shed_kc, jnp.inf, lats)
+                dropped_kc = dropped_kc | shed_kc
+                served_kc = mask_adm
         else:
             served_kc = None
-        # dropped requests carry the censor sentinel (> tau), so the
-        # shared reward rule scores them 0 without a special case
-        rewards = (lats <= cfg.tau).astype(jnp.float32)
-        issued = mask_all
+        with jax.named_scope(PH_ACCUMULATOR):
+            # dropped requests carry the censor sentinel (> tau), so the
+            # shared reward rule scores them 0 without a special case
+            rewards = (lats <= cfg.tau).astype(jnp.float32)
+            issued = mask_all
 
-        if trace:
-            ys = SimOutputs(
-                rewards=rewards, issued=issued, choices=choices,
-                latency=lats, proc_lat=procs, arrivals=arrivals,
-                queue=q_start, weights=w_now, true_mu=mu_true, regret=reg,
-                eps=strat["eps"](state), attempts=att_kc,
-                dropped=dropped_kc)
-        else:
-            acc = qm.update_accumulator(
-                acc, rewards=rewards, issued=issued, choices=choices,
-                procs=procs, arrivals=arrivals, regret=reg, mu=mu_true,
-                t_idx=t_idx, warmup_steps=warmup_steps, marks=marks,
-                ev_pre_steps=ev_pre_steps,
-                ev_bucket_steps=ev_bucket_steps, attempts=att_kc,
-                dropped=dropped_kc, brk_open=brk_open_step,
-                served=served_kc)
-            issf = issued.astype(jnp.float32)
-            ys = StepSeries(succ=(rewards * issf).sum(),
-                            issued=issf.sum(), regret=reg.sum(),
-                            attempts=att_kc.astype(jnp.float32).sum())
+            if trace:
+                ys = SimOutputs(
+                    rewards=rewards, issued=issued, choices=choices,
+                    latency=lats, proc_lat=procs, arrivals=arrivals,
+                    queue=q_start, weights=w_now, true_mu=mu_true,
+                    regret=reg, eps=strat["eps"](state), attempts=att_kc,
+                    dropped=dropped_kc)
+            else:
+                acc = qm.update_accumulator(
+                    acc, rewards=rewards, issued=issued, choices=choices,
+                    procs=procs, arrivals=arrivals, regret=reg, mu=mu_true,
+                    t_idx=t_idx, warmup_steps=warmup_steps, marks=marks,
+                    ev_pre_steps=ev_pre_steps,
+                    ev_bucket_steps=ev_bucket_steps, attempts=att_kc,
+                    dropped=dropped_kc, brk_open=brk_open_step,
+                    served=served_kc)
+                issf = issued.astype(jnp.float32)
+                ys = StepSeries(succ=(rewards * issf).sum(),
+                                issued=issf.sum(), regret=reg.sum(),
+                                attempts=att_kc.astype(jnp.float32).sum())
         if ctl_on:
             # step-end feedback: fold the fleet QoS/timeout totals into
             # the rolling EMAs the admission signal reads next step.
@@ -1059,31 +1084,33 @@ def build_sim_parts(
             # global or the replicated controller state would diverge
             # across shards — the control plane's ONE new in-loop
             # collective.
-            issf_c = issued.astype(jnp.float32)
-            attf_c = att_kc.astype(jnp.float32)
-            compl_c = issf_c * (1.0 - dropped_kc.astype(jnp.float32))
-            obs = jnp.stack([(rewards * issf_c).sum(), issf_c.sum(),
-                             (attf_c - compl_c).sum(), attf_c.sum()])
-            if pshard is not None:
-                obs = jax.lax.psum(obs, pshard.axis)
-            ctl = qc.control_observe(ccfg, ctl, obs, cfg.dt)
+            with jax.named_scope(PH_CONTROL):
+                issf_c = issued.astype(jnp.float32)
+                attf_c = att_kc.astype(jnp.float32)
+                compl_c = issf_c * (1.0 - dropped_kc.astype(jnp.float32))
+                obs = jnp.stack([(rewards * issf_c).sum(), issf_c.sum(),
+                                 (attf_c - compl_c).sum(), attf_c.sum()])
+                if pshard is not None:
+                    obs = jax.lax.psum(obs, pshard.axis)
+                ctl = qc.control_observe(ccfg, ctl, obs, cfg.dt)
         if rec_on:
             # flight recorder: append this step's events from
             # quantities the step already computed — per-player lanes
             # are shard-local, fleet lanes (marks, control actions) are
             # recorded only by the shard holding global player 0, so
             # there is no new collective on the players axis.
-            issf_r = issued.astype(jnp.float32)
-            rec = obr.record_step(
-                rcfg, rec, t_idx=t_idx, pids=pids, marks=marks,
-                miss_k=((1.0 - rewards) * issf_r).sum(-1),
-                iss_k=issf_r.sum(-1),
-                retry_drop_k=retry_drop_k,
-                shed_k=(shed_kc.astype(jnp.float32).sum(-1)
-                        if ctl_on and ccfg.admit else None),
-                open_now=(qb.breaker_is_open(brk, t) if brk_on
-                          else None),
-                ctl_deltas=ctl_deltas if ctl_on else None)
+            with jax.named_scope(PH_RECORDER):
+                issf_r = issued.astype(jnp.float32)
+                rec = obr.record_step(
+                    rcfg, rec, t_idx=t_idx, pids=pids, marks=marks,
+                    miss_k=((1.0 - rewards) * issf_r).sum(-1),
+                    iss_k=issf_r.sum(-1),
+                    retry_drop_k=retry_drop_k,
+                    shed_k=(shed_kc.astype(jnp.float32).sum(-1)
+                            if ctl_on and ccfg.admit else None),
+                    open_now=(qb.breaker_is_open(brk, t) if brk_on
+                              else None),
+                    ctl_deltas=ctl_deltas if ctl_on else None)
         return (state, q, act, acc, groups, pids, brk, ctl, rec), ys
 
     return init_fn, step_fn
@@ -1229,42 +1256,46 @@ def _build_tenant_parts(
         t_idx, nc, act, rtt_scale, cut_k, cut_m, s_m, k_step, group = xs
         # nc is the (S, K) per-tenant client schedule for this step
         t = t_idx.astype(jnp.float32) * cfg.dt
-        rtt_t = rtt * rtt_scale[None, :] + jnp.minimum(
-            cut_k[:, None], cut_m[None, :])
+        with jax.named_scope(PH_PLACEMENT):
+            rtt_t = rtt * rtt_scale[None, :] + jnp.minimum(
+                cut_k[:, None], cut_m[None, :])
 
-        changed = jnp.any(act != prev_active)
-        states = tuple(
-            jax.lax.cond(
-                changed,
-                lambda st, _s=s: strats[_s]["on_activity"](st, act,
-                                                           rtt_t, t),
-                lambda st: st, states[s])
-            for s in range(S))
-        if subset_maint:
+            changed = jnp.any(act != prev_active)
             states = tuple(
-                strats[s]["maintain_subset"](states[s], rtt_t, t, group)
+                jax.lax.cond(
+                    changed,
+                    lambda st, _s=s: strats[_s]["on_activity"](st, act,
+                                                               rtt_t, t),
+                    lambda st: st, states[s])
                 for s in range(S))
-        else:
-            lb_mask = jnp.zeros((K,), bool).at[group].set(
-                True, mode="drop")
-            states = tuple(
-                strats[s]["maintain"](states[s], rtt_t, t, lb_mask)
-                for s in range(S))
+        with jax.named_scope(PH_MAINTENANCE):
+            if subset_maint:
+                states = tuple(
+                    strats[s]["maintain_subset"](states[s], rtt_t, t, group)
+                    for s in range(S))
+            else:
+                lb_mask = jnp.zeros((K,), bool).at[group].set(
+                    True, mode="drop")
+                states = tuple(
+                    strats[s]["maintain"](states[s], rtt_t, t, lb_mask)
+                    for s in range(S))
 
         # oracle + regret per tenant at step start, against the
         # step-start TOTAL backlog and the tenant's effective row
-        q_tot0 = q.sum(0)
-        s_eff0 = eff_service(q, s_m)
-        mu_s = tuple(
-            _true_mu_tau(rtt_t, q_tot0, taus[s], cfg.proc_sigma,
-                         s_eff0[s])
-            for s in range(S))
-        reg_s = tuple(
-            step_regret(strats[s]["weights"](states[s]), mu_s[s], act)
-            for s in range(S))
-        mask_s = tuple(jnp.arange(C)[None, :] < nc[s][:, None]
-                       for s in range(S))
-        kidx = jnp.arange(K)
+        with jax.named_scope(PH_TRUE_MU):
+            q_tot0 = q.sum(0)
+            s_eff0 = eff_service(q, s_m)
+            mu_s = tuple(
+                _true_mu_tau(rtt_t, q_tot0, taus[s], cfg.proc_sigma,
+                             s_eff0[s])
+                for s in range(S))
+            reg_s = tuple(
+                step_regret(strats[s]["weights"](states[s]), mu_s[s], act)
+                for s in range(S))
+        with jax.named_scope(PH_ROUNDS):
+            mask_s = tuple(jnp.arange(C)[None, :] < nc[s][:, None]
+                           for s in range(S))
+            kidx = jnp.arange(K)
 
         def round_body(rc, r):
             states, q, arrivals = rc
@@ -1309,43 +1340,48 @@ def _build_tenant_parts(
             return (tuple(new_states), q, arrivals + arr_sm), \
                 tuple(outs)
 
-        (states, q, arr_sm), ys_r = jax.lax.scan(
-            round_body, (states, q, jnp.zeros((S, M), jnp.float32)),
-            jnp.arange(C))
+        with jax.named_scope(PH_ROUNDS):
+            (states, q, arr_sm), ys_r = jax.lax.scan(
+                round_body, (states, q, jnp.zeros((S, M), jnp.float32)),
+                jnp.arange(C))
 
         new_states, new_accs = [], []
         succ_v, iss_v, reg_v = [], [], []
         for s in range(S):
-            ch_r, lat_r, proc_r = ys_r[s]
-            choices, lats, procs = ch_r.T, lat_r.T, proc_r.T   # (K, C)
+            with jax.named_scope(PH_ROUNDS):
+                ch_r, lat_r, proc_r = ys_r[s]
+                choices, lats, procs = ch_r.T, lat_r.T, proc_r.T  # (K, C)
             st = states[s]
             if batched_record:
-                st = strats[s]["record_rings"](st, choices, lats, t,
-                                               mask_s[s])
-            rewards = (lats <= taus[s]).astype(jnp.float32)
-            issued = mask_s[s]
-            acc = qm.update_accumulator(
-                accs[s], rewards=rewards, issued=issued,
-                choices=choices, procs=procs, arrivals=arr_sm[s],
-                regret=reg_s[s], mu=mu_s[s], t_idx=t_idx,
-                warmup_steps=warmup_steps, marks=marks,
-                ev_pre_steps=ev_pre_steps,
-                ev_bucket_steps=ev_bucket_steps,
-                attempts=issued.astype(jnp.int32),
-                dropped=jnp.zeros_like(issued), brk_open=None,
-                served=None)
-            issf = issued.astype(jnp.float32)
-            succ_v.append((rewards * issf).sum())
-            iss_v.append(issf.sum())
-            reg_v.append(reg_s[s].sum())
+                with jax.named_scope(PH_RINGS):
+                    st = strats[s]["record_rings"](st, choices, lats, t,
+                                                   mask_s[s])
+            with jax.named_scope(PH_ACCUMULATOR):
+                rewards = (lats <= taus[s]).astype(jnp.float32)
+                issued = mask_s[s]
+                acc = qm.update_accumulator(
+                    accs[s], rewards=rewards, issued=issued,
+                    choices=choices, procs=procs, arrivals=arr_sm[s],
+                    regret=reg_s[s], mu=mu_s[s], t_idx=t_idx,
+                    warmup_steps=warmup_steps, marks=marks,
+                    ev_pre_steps=ev_pre_steps,
+                    ev_bucket_steps=ev_bucket_steps,
+                    attempts=issued.astype(jnp.int32),
+                    dropped=jnp.zeros_like(issued), brk_open=None,
+                    served=None)
+                issf = issued.astype(jnp.float32)
+                succ_v.append((rewards * issf).sum())
+                iss_v.append(issf.sum())
+                reg_v.append(reg_s[s].sum())
             new_states.append(st)
             new_accs.append(acc)
         # per-step series carry one scalar PER TENANT: the streamed
         # StepSeries fields come out (T, S)
-        ys = StepSeries(succ=jnp.stack(succ_v),
-                        issued=jnp.stack(iss_v),
-                        regret=jnp.stack(reg_v),
-                        attempts=jnp.stack(iss_v))
+        with jax.named_scope(PH_ACCUMULATOR):
+            ys = StepSeries(succ=jnp.stack(succ_v),
+                            issued=jnp.stack(iss_v),
+                            regret=jnp.stack(reg_v),
+                            attempts=jnp.stack(iss_v))
         return (tuple(new_states), q, act, tuple(new_accs), groups,
                 pids, None, None, None), ys
 
@@ -1867,7 +1903,7 @@ def run_sim_grid(
                 lambda x: jnp.broadcast_to(x[None], (S_run,) + x.shape),
                 drv_)
             return run_grid(rtts_, drv_b, keys_)
-    with _quiet_donation():
+    with _quiet_donation(), span("sim.grid_dispatch"):
         out = jax.jit(fn, donate_argnums=donate)(rtts, drv, keys)
     if pad:
         out = jax.tree.map(lambda x: x[:S], out)
@@ -2042,7 +2078,8 @@ def run_sim_stream(
 
     init_fn, chunk_fn = build_sim_chunks(
         strategy_name, cfg, K, M, warmup_steps=warmup_steps, **strategy_kw)
-    carry, keys = jax.jit(init_fn)(rtt, drv.active[0], key)
+    with span("sim.init"):
+        carry, keys = jax.jit(init_fn)(rtt, drv.active[0], key)
 
     ckpt = None
     start = 0
@@ -2069,11 +2106,12 @@ def run_sim_stream(
         """Fold pending device chunks into the host-side series."""
         nonlocal parts, done
         if parts:
-            host = jax.device_get(parts)
-            prev = [done] if done is not None else []
-            done = StepSeries(*(np.concatenate(
-                [np.asarray(getattr(p, f)) for p in prev + host])
-                for f in StepSeries._fields))
+            with span("sim.drain"):
+                host = jax.device_get(parts)
+                prev = [done] if done is not None else []
+                done = StepSeries(*(np.concatenate(
+                    [np.asarray(getattr(p, f)) for p in prev + host])
+                    for f in StepSeries._fields))
             parts = []
         return done
 
@@ -2085,9 +2123,10 @@ def run_sim_stream(
         if stop_at_step is not None and lo >= stop_at_step:
             break
         hi = min(lo + chunk_steps, T)
-        carry, ys = run_chunk(
-            rtt, carry, jnp.arange(lo, hi), qs.slice_drivers(drv, lo, hi),
-            keys[lo:hi])
+        with span("sim.chunk_dispatch"):
+            carry, ys = run_chunk(
+                rtt, carry, jnp.arange(lo, hi),
+                qs.slice_drivers(drv, lo, hi), keys[lo:hi])
         parts.append(ys)    # on-device O(chunk) scalars; the loop only
         # depends on the donated carry, so dispatch runs ahead and the
         # single device_get below drains everything at once
@@ -2096,11 +2135,13 @@ def run_sim_stream(
             # save() snapshots to numpy before returning, so the async
             # write never races the next chunk's donation; the manifest
             # meta identifies the run (restore ignores it)
-            ckpt.save(hi, {"carry": carry, "series": drain()},
-                      blocking=False, meta=ckpt_meta)
+            with span("sim.checkpoint_save"):
+                ckpt.save(hi, {"carry": carry, "series": drain()},
+                          blocking=False, meta=ckpt_meta)
     series = drain()
     if ckpt is not None:
-        ckpt.wait()
+        with span("sim.checkpoint_wait"):
+            ckpt.wait()
     ctl = carry[7]
     # the recorder ring rides the chunked carry (and therefore the
     # checkpoint template above) like any other state — chunked,

@@ -1,5 +1,12 @@
-"""Chrome trace-event export: recorder events and host-side timings as
-a Perfetto-loadable timeline.
+"""Named phases of a run, and Chrome trace-event export of recorder
+events and host-side timings as a Perfetto-loadable timeline.
+
+* :data:`STEP_PHASES` names the parts of one simulator step. The engine
+  wraps each in ``jax.named_scope``, so every instruction of the
+  compiled program carries its phase in its ``op_name`` metadata.
+* :func:`span` names a stretch of host work: a profiler annotation on
+  the device trace's clock (next to free when no profiler runs), also
+  recorded into a :class:`HostTimeline` when one is given.
 
 Two time domains share one trace, on separate process lanes:
 
@@ -8,9 +15,9 @@ Two time domains share one trace, on separate process lanes:
   thread (``tid``) per event-kind class, so Perfetto renders lanes like
   ``shard 0 / breaker_trip``. Fleet-level events (scenario marks,
   control actions) get their own ``fleet`` process.
-* **Host wall time** — :class:`HostTimeline` spans (chunk dispatch,
-  compile, export, …) as duration events on a ``host`` process,
-  re-based so the first span starts at t=0.
+* **Host wall time** — spans (:func:`span`) recorded into a
+  :class:`HostTimeline` (chunk dispatch, export, …) as duration events
+  on a ``host`` process, re-based to the timeline's construction.
 
 The emitted document is the standard JSON object format
 (``{"traceEvents": [...]}``) with ``ph`` "i" instant events for
@@ -25,9 +32,17 @@ import json
 import time
 from contextlib import contextmanager
 
+import jax
+
 from repro.obs import recorder as obr
 
 TRACE_SCHEMA_VERSION = 1
+
+# The phases of one simulator step, in the order the step runs them;
+# ``continuum.simulator`` names each with ``jax.named_scope``.
+STEP_PHASES = ("sim.control", "sim.placement", "sim.maintenance",
+               "sim.true_mu", "sim.rounds", "sim.rings", "sim.accumulator",
+               "sim.recorder")
 
 # fixed pid blocks so lanes sort stably in the UI
 _PID_FLEET = 1
@@ -80,10 +95,10 @@ def recorder_trace_events(rec_or_events, dt: float) -> list[dict]:
 
 
 class HostTimeline:
-    """Wall-clock span collector for the host side of a run (compile,
-    chunk dispatch, checkpoint write, export). Spans become ph="X"
-    complete events on the ``host`` process lane, re-based to the
-    timeline's construction time."""
+    """Wall-clock span sink for the host side of a run: each
+    :func:`span` given this timeline becomes a ph="X" complete event on
+    the ``host`` process lane, re-based to the timeline's construction
+    time."""
 
     def __init__(self):
         self._t0 = time.perf_counter()
@@ -95,22 +110,27 @@ class HostTimeline:
     def _now_us(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6
 
-    @contextmanager
     def span(self, name: str, cat: str = "host", **args):
-        t0 = self._now_us()
+        return span(name, self, cat, **args)
+
+
+@contextmanager
+def span(name: str, timeline: HostTimeline | None = None,
+         cat: str = "host", **args):
+    """A named stretch of host work. It is a ``jax.profiler``
+    annotation, so a profiler trace shows it on the host plane, on the
+    same clock as the device ops; without a profiler session it costs
+    next to nothing. With ``timeline`` it is also recorded there."""
+    t0 = timeline._now_us() if timeline is not None else 0.0
+    with jax.profiler.TraceAnnotation(name):
         try:
             yield
         finally:
-            self.events.append({
-                "ph": "X", "pid": _PID_HOST, "tid": 1, "name": name,
-                "cat": cat, "ts": t0, "dur": self._now_us() - t0,
-                **({"args": args} if args else {})})
-
-    def instant(self, name: str, cat: str = "host", **args):
-        self.events.append({
-            "ph": "i", "s": "t", "pid": _PID_HOST, "tid": 1,
-            "name": name, "cat": cat, "ts": self._now_us(),
-            **({"args": args} if args else {})})
+            if timeline is not None:
+                timeline.events.append({
+                    "ph": "X", "pid": _PID_HOST, "tid": 1, "name": name,
+                    "cat": cat, "ts": t0, "dur": timeline._now_us() - t0,
+                    **({"args": args} if args else {})})
 
 
 def chrome_trace(*event_lists, meta: dict | None = None) -> dict:

@@ -144,8 +144,8 @@ def test_recorder_trace_and_host_timeline(storm_out):
     cfg, out = storm_out
     evs = trace.recorder_trace_events(out.rec, cfg.dt)
     tl = trace.HostTimeline()
-    with tl.span("phase", "test"):
-        tl.instant("ping")
+    with trace.span("phase", tl, "test"):
+        pass
     doc = trace.chrome_trace(evs, tl.events, meta={"run": "t"})
     assert trace.validate_chrome_trace(doc) == []
     insts = [e for e in doc["traceEvents"] if e["ph"] == "i"
