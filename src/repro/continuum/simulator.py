@@ -27,8 +27,9 @@ Engine structure (streaming-first):
   feedback control stay interleaved round by round, so an in-step trip
   steers the remaining rounds exactly as before; with the fused request
   path the expensive (K, M, R)/(K, Rq) ring writes are still deferred
-  into ONE ``record_rings_batch`` scatter per step (its rank/offset
-  arithmetic is round-order-free). Fused and sequential paths remain
+  into ONE dense ``record_rings_batch`` write per step (its rank/offset
+  arithmetic is round-order-free; a masked select, not a scatter, which
+  a TPU would apply one index at a time). Fused and sequential paths remain
   bit-for-bit identical (tests/test_bandit_batch.py).
 * **Metrics stream by default-capable mode**: with ``trace=False`` the
   scan carries a ``MetricAccumulator`` (O(K·M) sufficient statistics
@@ -831,7 +832,7 @@ def build_sim_parts(
         # in-step cooldown trip steers the remaining rounds exactly as
         # with per-round `record`. With the fused path the expensive
         # (K, M, R)/(K, Rq) ring writes are deferred and land in ONE
-        # fused scatter per step (`record_rings_batch`); the sequential
+        # dense masked write per step (`record_rings_batch`); the sequential
         # fallback lets the strategy read its own per-request state
         # between rounds (Dec-SARSA). Bit-for-bit identical paths
         # (tests/test_bandit_batch.py). ---
@@ -1018,8 +1019,8 @@ def build_sim_parts(
                 att_kc = att_r.T              # (K, C) i32 attempts
                 dropped_kc = drop_r.T         # (K, C) bool
             if batched_record:
-                # all C*A attempts land in the step's ONE fused ring
-                # scatter, columns in chronological (round-major,
+                # all C*A attempts land in the step's ONE dense ring
+                # write, columns in chronological (round-major,
                 # attempt-minor) order — record_rings_batch is generic
                 # in its column count
                 with jax.named_scope(PH_RINGS):

@@ -206,7 +206,7 @@ def record(
 
     Masked players leave the state untouched. Repeated calls handle
     multiple requests per player per step; ``record_batch`` ingests all
-    of them in one fused scatter instead.
+    of them in one dense ring write instead.
     """
     K, M, R = state.lat_buf.shape
     kidx = jnp.arange(K)
@@ -247,7 +247,7 @@ def record_feedback(
     NO ring writes. Pair with ``record_rings_batch`` — the simulator
     interleaves this with selection (so in-step trips still steer the
     remaining rounds, exactly like sequential ``record``) and defers
-    the expensive (K, M, R) scatters to one fused write per step."""
+    the expensive (K, M, R) ring writes to one dense write per step."""
     reward = (latency <= params.tau).astype(jnp.float32)
     return _record_control(state, params, choice, reward, t, mask)
 
@@ -261,48 +261,65 @@ def record_rings_batch(
     mask: jax.Array,        # (K, C) bool: request actually issued
 ) -> BanditState:
     """Ring-buffer half of ``record_batch``: all C requests' latency /
-    timestamp / reward samples land in one fused scatter.
+    timestamp / reward samples land in one dense masked write.
 
     Ring slots are computed with per-(player, arm) offset arithmetic —
     the j-th masked write of the batch to arm m lands at
-    ``(ptr + j) % R`` — so the C rounds of (K, M, R)/(K, Rq) scatters
-    collapse to one. Writes that a later same-slot write of the same
-    batch would overwrite are dropped up front, keeping scatter indices
-    unique (deterministic). Final buffer contents are bit-for-bit what
+    ``(ptr + j) % R`` — so the C rounds of (K, M, R)/(K, Rq) writes
+    collapse to one pass. Writes that a later same-slot write of the
+    same batch would overwrite are dropped up front, so every ring slot
+    has at most one writer. Final buffer contents are bit-for-bit what
     C sequential ``record`` calls leave behind; control flow
     (err/trips/weights) is NOT applied here.
+
+    The write is a chain of C ``where`` selects over each buffer, and
+    the index lookups are one-hot reductions, not a scatter and
+    gathers: a TPU applies a scatter one index at a time (about 70 ns
+    an index, masked columns included), so the K·C-index scatter cost
+    far more than the O(M·R·C) elementwise pass per player that
+    replaces it. ``where`` copies every value bit for bit, the censor
+    sentinel and ``inf`` included.
     """
-    K, M, R = state.lat_buf.shape
-    C = choices.shape[1]
+    M, R = state.lat_buf.shape[1:]
     Rq = state.r_buf.shape[1]
-    kk = jnp.broadcast_to(jnp.arange(K)[:, None], (K, C))
-    t_arr = jnp.broadcast_to(jnp.asarray(t, jnp.float32), (K, C))
+    t = jnp.asarray(t, jnp.float32)
     reward = (latencies <= params.tau).astype(jnp.float32)
     maski = mask.astype(jnp.int32)
 
+    def pick(x, hot):           # x[k, c, choices[k, c]] without a gather
+        return jnp.where(hot, x, 0).sum(-1)
+
     # --- latency rings: offset arithmetic over per-(k, arm) ranks ---
-    onehot = (choices[..., None] == jnp.arange(M)) & mask[..., None]
-    cnt = jnp.cumsum(onehot.astype(jnp.int32), axis=1)        # inclusive
+    chosen = choices[..., None] == jnp.arange(M)              # (K, C, M)
+    onehot = (chosen & mask[..., None]).astype(jnp.int32)
+    cnt = jnp.cumsum(onehot, axis=1)                          # inclusive
     total = cnt[:, -1, :]                                     # (K, M)
-    rank = jnp.take_along_axis(                               # exclusive
-        cnt - onehot.astype(jnp.int32), choices[..., None], axis=2)[..., 0]
-    p0 = jnp.take_along_axis(state.ptr, choices, axis=1)      # (K, C)
-    slot = (p0 + rank) % R
-    tot_c = jnp.take_along_axis(total, choices, axis=1)
+    rank = pick(cnt - onehot, chosen)                         # exclusive
+    p0 = pick(state.ptr[:, None, :], chosen)                  # (K, C)
+    tot_c = pick(total[:, None, :], chosen)
     keep = mask & (rank >= tot_c - R)       # drop within-batch overwrites
-    slot = jnp.where(keep, slot, R)         # out of bounds => dropped
-    lat_buf = state.lat_buf.at[kk, choices, slot].set(latencies, mode="drop")
-    ts_buf = state.ts_buf.at[kk, choices, slot].set(t_arr, mode="drop")
+    # each write's flat (arm, slot) cell of its player's rings; -1: none
+    dst = jnp.where(keep, choices * R + (p0 + rank) % R, -1)
+    cell = jnp.arange(M * R).reshape(M, R)
+    lat_buf, ts_buf = state.lat_buf, state.ts_buf
+    for d, lat in zip(jnp.unstack(dst, axis=1),
+                      jnp.unstack(latencies, axis=1)):
+        hit = d[:, None, None] == cell
+        lat_buf = jnp.where(hit, lat[:, None, None], lat_buf)
+        ts_buf = jnp.where(hit, t, ts_buf)
     ptr = (state.ptr + total) % R
 
     # --- per-player reward ring ---
     crank = jnp.cumsum(maski, axis=1) - maski                 # (K, C)
     totk = maski.sum(1)                                       # (K,)
-    rslot = (state.rptr[:, None] + crank) % Rq
     keep_r = mask & (crank >= totk[:, None] - Rq)
-    rslot = jnp.where(keep_r, rslot, Rq)
-    r_buf = state.r_buf.at[kk, rslot].set(reward, mode="drop")
-    rts_buf = state.rts_buf.at[kk, rslot].set(t_arr, mode="drop")
+    rslot = jnp.where(keep_r, (state.rptr[:, None] + crank) % Rq, -1)
+    r_buf, rts_buf = state.r_buf, state.rts_buf
+    for d, r in zip(jnp.unstack(rslot, axis=1),
+                    jnp.unstack(reward, axis=1)):
+        hit = d[:, None] == jnp.arange(Rq)
+        r_buf = jnp.where(hit, r[:, None], r_buf)
+        rts_buf = jnp.where(hit, t, rts_buf)
     rptr = (state.rptr + totk) % Rq
 
     return state._replace(
@@ -318,7 +335,7 @@ def record_batch(
     t: jax.Array,
     mask: jax.Array,        # (K, C)
 ) -> BanditState:
-    """Ingest all C requests of a step: one fused ring scatter plus an
+    """Ingest all C requests of a step: one dense ring write plus an
     in-order replay of the cheap (K, M) control flow. Bit-for-bit
     equal to C sequential ``record`` calls (tests/test_bandit_batch.py).
 

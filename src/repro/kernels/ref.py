@@ -272,8 +272,10 @@ def bandit_maintenance_stats(
 # and the deferred ring scatter. Mirrors, op for op:
 #   repro.core.swrr.swrr_select
 #   repro.core.bandit._record_control      (via record_feedback)
-#   repro.core.bandit.record_rings_batch
 #   the round scan in repro.continuum.simulator.build_sim_parts
+# and, in result only, repro.core.bandit.record_rings_batch: that writes
+# the rings with dense masked selects, ``_ring_scatter`` with a scatter
+# to the same slots, and both leave the same rings and pointers.
 # Kept self-contained (no repro.core imports) for the same reason as
 # ``bandit_maintenance_stats``: core -> kernels -> core would cycle.
 #
@@ -312,7 +314,8 @@ class RoundStepOut(NamedTuple):
 
 def _ring_scatter(lat_buf, ts_buf, ptr, r_buf, rts_buf, rptr,
                   choices, lats, t, mask, tau):
-    """`core.bandit.record_rings_batch` mirrored op-for-op."""
+    """`core.bandit.record_rings_batch`'s rings and pointers, bit for
+    bit, written with a scatter where that function selects densely."""
     K, M, R = lat_buf.shape
     C = choices.shape[1]
     Rq = r_buf.shape[1]

@@ -6,6 +6,8 @@ kernel (interpret mode) must match the pure-jnp Silverman/KDE/quantile
 composition, and the subset/batched drivers must commit exactly what
 the full-width versions do.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,19 +16,25 @@ import pytest
 from repro.core import (BanditParams, init_state, maintenance,
                         maintenance_subset, record, record_batch)
 from repro.core import kde as core_kde
+from repro.core.bandit import censored_latency, record_rings_batch
 from repro.kernels import ref
 from repro.kernels.kde import fused_maintenance
 
 P = BanditParams()
 
 
-def _random_trace(rng, K, M, C, full_mask=False):
+def _random_trace(rng, K, M, C, full_mask=False, p_mask=0.7, special=None):
+    """``special``: a latency planted in about a quarter of the columns,
+    which the rings must hold bit for bit."""
     choices = jnp.asarray(rng.integers(0, M, (K, C)), jnp.int32)
-    lats = jnp.asarray(rng.uniform(0.005, 0.3, (K, C)), jnp.float32)
+    lats = rng.uniform(0.005, 0.3, (K, C))
+    if special is not None:
+        lats = np.where(rng.random((K, C)) < 0.25, special, lats)
+    lats = jnp.asarray(lats, jnp.float32)
     if full_mask:
         mask = jnp.ones((K, C), bool)
     else:
-        mask = jnp.asarray(rng.random((K, C)) < 0.7)
+        mask = jnp.asarray(rng.random((K, C)) < p_mask)
     return choices, lats, mask
 
 
@@ -36,23 +44,46 @@ def _assert_states_equal(a, b):
             np.asarray(xa), np.asarray(xb), err_msg=f"field {name}")
 
 
-@pytest.mark.parametrize("K,M,C,ring,steps", [
-    (5, 4, 6, 8, 12),       # ring wraps across steps
-    (3, 2, 8, 64, 4),       # multiple writes per (k, arm) per batch
-    (7, 5, 4, 16, 6),
+@pytest.mark.parametrize("K,M,C,ring,reward_ring,p_mask,special,steps", [
+    (5, 4, 6, 8, 16, 0.7, None, 12),        # ring wraps across steps
+    (3, 2, 8, 64, 16, 0.7, None, 4),        # several writes per (k, arm)
+    (7, 5, 4, 16, 16, 0.7, None, 6),
+    # the testbed's retry-storm step: 8 rounds x 3 attempts, about half
+    # the columns issued; every reward ring and 50 of the 300 latency
+    # rings wrap within the steps
+    (30, 10, 24, 64, 512, 0.5, None, 48),
+    # the timeout censor sentinel and inf must be copied bit for bit
+    (5, 4, 6, 8, 16, 0.7, censored_latency(0.09, 0.08), 12),
+    (5, 4, 6, 8, 16, 0.7, np.inf, 12),
 ])
-def test_record_batch_matches_sequential(K, M, C, ring, steps):
+def test_record_batch_matches_sequential(K, M, C, ring, reward_ring, p_mask,
+                                         special, steps):
     rng = np.random.default_rng(0)
-    st_a = init_state(K, M, P, ring=ring, reward_ring=16,
+    st_a = init_state(K, M, P, ring=ring, reward_ring=reward_ring,
                       key=jax.random.PRNGKey(0))
     st_b = st_a
     for i in range(steps):
-        choices, lats, mask = _random_trace(rng, K, M, C)
+        choices, lats, mask = _random_trace(rng, K, M, C, p_mask=p_mask,
+                                            special=special)
         t = jnp.float32(i * 0.1)
         st_a = record_batch(st_a, P, choices, lats, t, mask)
         for c in range(C):
             st_b = record(st_b, P, choices[:, c], lats[:, c], t, mask[:, c])
         _assert_states_equal(st_a, st_b)
+
+
+def test_record_rings_batch_lowers_without_scatter_or_gather():
+    """The ring write stays dense at the testbed's shape (K=30, M=10,
+    8 rounds x 3 attempts, rings of 64 and 512): a TPU applies a
+    scatter or gather one index at a time, and the four K*C-index ring
+    scatters once took over a quarter of the simulator step."""
+    K, M, C, R, Rq = 30, 10, 24, 64, 512
+    st = init_state(K, M, P, ring=R, reward_ring=Rq)
+    low = jax.jit(record_rings_batch, static_argnums=1).lower(
+        st, P, jnp.zeros((K, C), jnp.int32), jnp.zeros((K, C), jnp.float32),
+        jnp.float32(0.0), jnp.zeros((K, C), bool))
+    for text in (low.as_text(), low.compile().as_text()):
+        assert not re.findall(r'(?:gather|scatter)"?\(', text)
 
 
 def test_record_batch_overflow_within_batch():
